@@ -163,11 +163,15 @@ def _gather_blocks(x, ids, epb, n_blocks):
     return flat.reshape(n_blocks, epb)[ids]
 
 
-def _copy_to_host_async(arr) -> None:
+def copy_to_host_async(arr) -> None:
+    """Start ``arr``'s device→host copy without blocking. A backend without
+    async transfer reports UNIMPLEMENTED, and the later gather then simply
+    blocks. Any other error (a deleted buffer, a lost device) propagates."""
     try:
         arr.copy_to_host_async()
-    except Exception:
-        pass                   # backend without async transfer: gather blocks
+    except jax.errors.JaxRuntimeError as e:
+        if not str(e).startswith("UNIMPLEMENTED"):
+            raise
 
 
 class _Staged:
@@ -200,7 +204,7 @@ class _Staged:
         ent = self.ent
         if len(dirty) > self.tracker.dense_fallback_frac * len(ent.refs):
             self.dense = True
-            _copy_to_host_async(self.leaf)
+            copy_to_host_async(self.leaf)
             return
         now = time.monotonic()
         if now - ent.verified_at > self.tracker.touch_interval_s:
@@ -247,7 +251,7 @@ class _Staged:
             ids = np.pad(self._dirty, (0, k_pad - k), mode="edge")
             self._gathered = _gather_blocks(self.leaf, jnp.asarray(ids),
                                             epb, len(ent.refs))
-            _copy_to_host_async(self._gathered)
+            copy_to_host_async(self._gathered)
 
     def finish(self) -> tuple[DeltaBlocks, int, int] | None:
         """Materialize: returns (piece payload, d2h bytes, skipped bytes),
@@ -351,7 +355,7 @@ class DeviceDeltaTracker:
         if ent is not None and self._usable(ent, leaf, codec):
             fp, diff = fingerprint_diff(leaf, ent.fp,
                                         block_bytes=self.chunk_size)
-            _copy_to_host_async(diff)
+            copy_to_host_async(diff)
         else:
             fp, diff, ent = fingerprint_blocks(
                 leaf, block_bytes=self.chunk_size), None, None
@@ -416,7 +420,7 @@ class DeviceDeltaTracker:
             elif usable:
                 fp, diff = fingerprint_diff(leaf, ent.fp,
                                             block_bytes=self.chunk_size)
-                _copy_to_host_async(diff)
+                copy_to_host_async(diff)
             else:
                 fp, diff = fingerprint_blocks(
                     leaf, block_bytes=self.chunk_size), None
@@ -427,7 +431,7 @@ class DeviceDeltaTracker:
                 continue                           # dense path this save
             if diff is None:
                 diff = fp != ent.fp
-                _copy_to_host_async(diff)
+                copy_to_host_async(diff)
             staged[name] = _Staged(self, name, leaf, ent, fp, diff, codec)
         with self._lock:
             self.stats["fallbacks"] += fallbacks

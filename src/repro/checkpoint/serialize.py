@@ -210,8 +210,12 @@ def quantize(arr: np.ndarray, quant: str) -> tuple[np.ndarray, float | None]:
     can run on memoryview windows without a ``.tobytes()`` materialization.
     """
     if quant == "int8":
-        absmax = np.float32(np.max(np.abs(arr.astype(np.float32)))) if arr.size \
-            else np.float32(0.0)
+        # one float32 temporary per tensor: encode workers quantize several
+        # moment tensors at once, and each extra full-size temporary is
+        # host memory the save holds on top of its snapshot
+        x = arr.astype(np.float32, copy=False)
+        absmax = (np.maximum(np.max(x), -np.min(x)) if x.size
+                  else np.float32(0.0))
         scale, inv = int8_scale_inv(absmax)
         # multiply-only elementwise step, float32 scalar arithmetic: this is
         # what keeps a host quantize bit-identical to the on-device kernel
@@ -219,8 +223,10 @@ def quantize(arr: np.ndarray, quant: str) -> tuple[np.ndarray, float | None]:
         # division into reciprocal-multiply — identical payload bytes are what
         # let urgent (device-quantized) and periodic (host-quantized) saves of
         # the same state dedup to the same pool chunks
-        q = np.clip(np.round(arr.astype(np.float32) * inv), -127, 127).astype(np.int8)
-        return q, float(scale)
+        y = x * inv
+        np.round(y, out=y)
+        np.clip(y, -127, 127, out=y)
+        return y.astype(np.int8), float(scale)
     return np.ascontiguousarray(arr), None
 
 

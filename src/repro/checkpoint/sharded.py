@@ -59,7 +59,8 @@ from . import manifest as mf
 from . import serialize as ser
 from ..distributed import multihost
 from ..distributed.sharding import addressable_shard_spans
-from .device_delta import DeltaBlocks, DeviceDeltaTracker, write_delta_blocks_piece
+from .device_delta import (DeltaBlocks, DeviceDeltaTracker, copy_to_host_async,
+                           write_delta_blocks_piece)
 from .ioutil import fsync_dir
 
 Index = tuple[tuple[int, int], ...]
@@ -117,17 +118,13 @@ def _slices_to_index(slices, shape) -> Index:
 
 
 def _stage_async(leaf) -> None:
-    """Issue the device→host DMA for one array without blocking. Best-effort:
-    backends without async transfer simply block in the gather pass."""
-    try:
-        if leaf.is_fully_replicated:
-            leaf.copy_to_host_async()
-        else:
-            for shard in leaf.addressable_shards:
-                if shard.replica_id == 0:
-                    shard.data.copy_to_host_async()
-    except Exception:
-        pass
+    """Issue the device→host DMA for one array without blocking."""
+    if leaf.is_fully_replicated:
+        copy_to_host_async(leaf)
+    else:
+        for shard in leaf.addressable_shards:
+            if shard.replica_id == 0:
+                copy_to_host_async(shard.data)
 
 
 def prestage(state, tracker: DeviceDeltaTracker | None = None):

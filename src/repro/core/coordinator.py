@@ -53,6 +53,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+import jax
+
 from ..checkpoint import backend as chunk_backend
 from ..checkpoint import codec_sched
 from ..checkpoint.async_ckpt import AsyncCheckpointer
@@ -75,16 +77,30 @@ log = logging.getLogger("spoton")
 _STORAGE_FAULT_ERRNOS = frozenset(retry.PERSISTENT_ERRNOS) | {errno.EIO}
 
 
-def _storage_fault(exc: BaseException | None) -> bool:
-    """True when ``exc`` (or any chained cause — async failures arrive
-    wrapped in RuntimeError) is a persistent storage-level fault."""
+def _chain(exc: BaseException | None):
+    """``exc`` and its chained causes (async failures arrive wrapped in
+    RuntimeError), nearest first."""
     seen = 0
     while exc is not None and seen < 8:
-        if isinstance(exc, OSError) and exc.errno in _STORAGE_FAULT_ERRNOS:
-            return True
+        yield exc
         exc = exc.__cause__ or exc.__context__
         seen += 1
-    return False
+
+
+def _storage_fault(exc: BaseException | None) -> bool:
+    """True when ``exc`` or a chained cause is a persistent storage-level
+    fault."""
+    return any(isinstance(e, OSError) and e.errno in _STORAGE_FAULT_ERRNOS
+               for e in _chain(exc))
+
+
+def _raise_device_fault(exc: BaseException) -> None:
+    """Re-raise ``exc`` when it or a chained cause is a device runtime error
+    (OOM, failed compile, lost chip). ``JaxRuntimeError`` subclasses
+    RuntimeError, so the save paths' degradation handlers would otherwise
+    count it as one failed save and train on: the device is not storage."""
+    if any(isinstance(e, jax.errors.JaxRuntimeError) for e in _chain(exc)):
+        raise exc
 
 
 class Signal(enum.Enum):
@@ -354,6 +370,10 @@ class SpotOnCoordinator:
 
     def _save_periodic(self, step: int, state, *, stat: str = "periodic") -> bool:
         t0 = self.clock.now()
+        # the cadence counts from when a save is decided, not from when its
+        # modeled cost has been charged: stamping after the charge would
+        # push every later save back by that cost
+        self._last_periodic_at = t0
         if self._degraded_until is not None:
             if t0 < self._degraded_until:
                 # skip-and-alert: storage said "full/broken" recently enough
@@ -362,7 +382,6 @@ class SpotOnCoordinator:
                 # reports surface the degradation window.
                 self.stats.saves_degraded += 1
                 self.ledger.count("saves_degraded", 1)
-                self._last_periodic_at = t0
                 return False
             self._degraded_until = None  # cooldown over: probe storage again
         # prestage at decision time: with the tracker, fingerprint + diff
@@ -392,9 +411,9 @@ class SpotOnCoordinator:
             # a failed periodic save must not kill training: the committed
             # history is untouched (atomic commit) and the next cadence
             # retries with fresher state
+            _raise_device_fault(e)
             log.warning("periodic checkpoint failed: %s", e)
             self.stats.periodic_failures += 1
-            self._last_periodic_at = self.clock.now()
             if _storage_fault(e):
                 # ENOSPC/EDQUOT/EROFS, or EIO that already exhausted the IO
                 # layer's bounded retries: a *state*, not an event — enter
@@ -415,7 +434,6 @@ class SpotOnCoordinator:
         else:
             self.stats.periodic_ckpts += 1
         self.stats.ckpt_time_s += (self.clock.now() - t0)
-        self._last_periodic_at = self.clock.now()
         return True
 
     def _save_termination(self, step: int, state, deadline: float) -> bool:
@@ -428,6 +446,7 @@ class SpotOnCoordinator:
         # urgent saves bypass the device-delta tracker entirely — the notice
         # window cannot pay digest kernels whose results extract would then
         # discard — so the prestage is the plain full-state DMA kick
+        w0 = _time.perf_counter()
         state = prestage(state)
         try:
             if self._async is not None:
@@ -439,9 +458,13 @@ class SpotOnCoordinator:
                 info = self.store.save_snapshot(snap, kind="termination",
                                                 extra=self._tags())
         except (TimeoutError, RuntimeError, OSError) as e:
+            _raise_device_fault(e)
             log.warning("termination checkpoint failed: %s", e)
             self.stats.termination_failures += 1
             return False
+        # wall time from the save's start to its durable commit: what the
+        # provider's notice window has to cover
+        self.ledger.observe("urgent_save_wall", _time.perf_counter() - w0)
         self._account_extract(d2h_bytes=info.d2h_bytes,
                               d2h_skipped=info.d2h_bytes_skipped,
                               stall_s=info.save_stall_ms / 1e3)
@@ -635,6 +658,7 @@ class SpotOnCoordinator:
             try:
                 self._async.wait_until_finished()
             except RuntimeError as e:
+                _raise_device_fault(e)
                 log.warning("async checkpoint write failed at flush: %s", e)
                 self.stats.periodic_failures += 1
                 if _storage_fault(e):
@@ -646,6 +670,7 @@ class SpotOnCoordinator:
             try:
                 self._async.close()
             except RuntimeError as e:
+                _raise_device_fault(e)
                 log.warning("async checkpoint write failed at close: %s", e)
                 self.stats.periodic_failures += 1
             self._drain_async_stats()

@@ -1,11 +1,17 @@
-"""Pallas TPU kernels for the workload's compute hot spots (the paper itself
-contributes no kernels — these belong to the substrate being checkpointed).
+"""Pallas TPU kernels.
 
 Each subpackage: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper), ref.py (pure-jnp oracle). Validated in interpret=True on CPU;
-TPU is the compile target. The model's XLA paths (models/layers.py chunked
-attention, associative scans) implement identical semantics and serve as the
-lowering path on non-TPU backends; on TPU, ops here are the drop-in hot path.
+TPU is the compile target.
+
+Two families run in the checkpoint path on a TPU: ``fingerprint`` (the
+device-delta tracker's per-block digests) and ``quantize`` (on-device int8
+moments for urgent saves, and the restore's dequantize);
+``tests/test_tpu_compile.py`` compiles both for a described v5e. The
+attention and scan kernels (flash/decode attention, ``ssm_scan``,
+``rglru_scan``) mirror the model's XLA paths (models/layers.py chunked
+attention, associative scans) and are tested against them, but ``models/``
+never calls them: training and serving run the XLA paths on every backend.
 """
 
 from .decode_attention import decode_attention_ref, flash_decode

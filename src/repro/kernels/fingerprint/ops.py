@@ -82,8 +82,7 @@ def _fp_pallas(x, wpb, n_blocks, interpret):
     # inside the kernel body.)
     rows = wpb // LANES
     w = _words_impl(x, wpb, n_blocks).reshape(n_blocks * rows, LANES)
-    return fingerprint_blocks_2d(w, rows_per_block=rows,
-                                 interpret=interpret).reshape(n_blocks)
+    return fingerprint_blocks_2d(w, rows_per_block=rows, interpret=interpret)
 
 
 def _single_device(x) -> bool:

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -142,7 +141,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, window=0, scale=None,
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -275,7 +274,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
             pltpu.VMEM((block_k, hd), jnp.float32),
             pltpu.VMEM((block_k, hd), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, dsum)
@@ -299,7 +298,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal=True, window=0,
         out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, hd), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, dsum)

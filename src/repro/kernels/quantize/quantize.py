@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
@@ -79,7 +78,7 @@ def absmax_2d(x2d, *, block_rows: int = DEFAULT_BLOCK_ROWS, interpret=False):
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.SMEM((1, 1), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2d)
@@ -101,7 +100,7 @@ def quantize_2d(inv, x2d, *, block_rows: int = DEFAULT_BLOCK_ROWS,
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), jnp.int8),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(inv, jnp.float32).reshape(1, 1), x2d)
@@ -123,7 +122,7 @@ def dequantize_2d(scale, q2d, *, out_dtype, block_rows: int = DEFAULT_BLOCK_ROWS
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), out_dtype),
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(scale, jnp.float32).reshape(1, 1), q2d)

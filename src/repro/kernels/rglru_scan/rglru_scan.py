@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 DEFAULT_BLOCK_W = 512
 DEFAULT_CHUNK = 128
@@ -71,7 +70,7 @@ def rglru_scan(a, b, *, block_w=DEFAULT_BLOCK_W, chunk=DEFAULT_CHUNK,
             jax.ShapeDtypeStruct((Bb, W), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

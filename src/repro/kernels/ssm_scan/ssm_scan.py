@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .. import compat
 
 DEFAULT_BLOCK_D = 512
 DEFAULT_CHUNK = 64
@@ -95,7 +94,7 @@ def ssm_scan(u, delta, A, B, C, D, *, block_d=DEFAULT_BLOCK_D,
             jax.ShapeDtypeStruct((Bb, DI, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, N), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(u, delta, A, B, C, D)

@@ -9,27 +9,32 @@ from __future__ import annotations
 
 import jax
 
-# TPU v5e hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link
+# Published per-chip peaks (roofline denominators), keyed by
+# ``jax.Device.device_kind``. Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect over four links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9,
+                    "ici_link_bw": 50e9},
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; a device missing from
+    ``PEAKS`` is an error, never another chip's numbers."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """``jax.make_mesh`` across the AxisType API drift.
-
-    Newer JAX grew ``jax.sharding.AxisType`` and an ``axis_types`` kwarg on
-    ``make_mesh`` (explicit-sharding meshes); 0.4.x has neither. We always
-    want the default Auto axes, so pass the kwarg only where it exists —
-    probed once on the live module, not by version string.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(axes)
-    return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
+    """``jax.make_mesh`` with default Auto axes on every dimension."""
+    kwargs = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         **kwargs)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

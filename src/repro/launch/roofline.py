@@ -14,7 +14,7 @@ from __future__ import annotations
 import re
 
 from ..models.config import ModelConfig, SSMConfig, RGLRUConfig
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from .mesh import peaks_for
 
 # ---------------------------------------------------------------------------
 # analytic model FLOPs (the "useful work" numerator)
@@ -165,10 +165,11 @@ def collective_bytes(hlo_text: str) -> dict[str, float]:
 # ---------------------------------------------------------------------------
 
 def roofline_terms(*, per_device_flops: float, per_device_bytes: float,
-                   per_device_coll_bytes: float) -> dict:
-    compute_s = per_device_flops / PEAK_FLOPS_BF16
-    memory_s = per_device_bytes / HBM_BW
-    coll_s = per_device_coll_bytes / ICI_BW
+                   per_device_coll_bytes: float, device_kind: str) -> dict:
+    peaks = peaks_for(device_kind)
+    compute_s = per_device_flops / peaks["flops_bf16"]
+    memory_s = per_device_bytes / peaks["hbm_bw"]
+    coll_s = per_device_coll_bytes / peaks["ici_link_bw"]
     terms = {"compute_s": compute_s, "memory_s": memory_s,
              "collective_s": coll_s}
     dominant = max(terms, key=terms.get)

@@ -91,36 +91,76 @@ def sweep_compilation_cache(cache_dir: str, *,
     return removed
 
 
-def setup_compilation_cache(cache_dir: str) -> bool:
-    """Point XLA's persistent compilation cache at a directory that survives
-    instance replacement (the checkpoint volume is the natural home).
+def setup_compilation_cache(cache_dir: str) -> str | None:
+    """Turn on XLA's persistent compilation cache.
 
     This is the compile leg of the fast-resume pipeline: a replacement
-    instance deserializes the step executable from the shared cache instead
-    of re-running XLA passes, so `SpotTrainer.resume`'s overlapped
-    precompile degenerates to a disk read. Thresholds are zeroed because on
-    a spot fleet *every* recompile sits inside the MTTR window. Best-effort
-    across JAX versions; returns False when unsupported.
+    instance deserializes the step executable from the cache instead of
+    re-running XLA passes, so `SpotTrainer.resume`'s overlapped precompile
+    degenerates to a disk read. Where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX already reads it: no directory is set here and None is returned,
+    because that directory belongs to whoever set the variable. Otherwise
+    the cache goes to ``cache_dir`` (made absolute, never a temporary name),
+    which is returned: the program owns it, so ``build_run`` may sweep it.
+    Thresholds are zeroed because on a spot fleet *every* recompile sits
+    inside the MTTR window.
     """
     import jax
 
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                          ("jax_persistent_cache_min_entry_size_bytes", 0)):
-            try:
-                jax.config.update(knob, val)
-            except (AttributeError, ValueError):  # knob renamed/absent
-                pass
-        return True
-    except (AttributeError, ValueError, OSError):
-        try:  # pre-config-flag JAX: explicit initializer API
-            from jax.experimental.compilation_cache import compilation_cache
-            compilation_cache.set_cache_dir(cache_dir)
-            return True
-        except Exception:
-            return False
+    owned = None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        owned = os.path.abspath(cache_dir)
+        os.makedirs(owned, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", owned)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    return owned
+
+
+def build_run(cfg, *, clock, schedule, ckpt_dir: str, steps: int,
+              mode: str = "transparent", interval: float = 60.0,
+              stages: int = 5, batch: int = 8, seq_len: int = 64,
+              seed: int = 0, remat: str = "none", microbatches: int = 1,
+              provision_delay: float = 5.0, quantize_moments: bool = False,
+              step_time_s: float | None = None,
+              compile_cache_dir: str | None = None):
+    """Wire one Spot-on-protected training run: a delta-mode
+    ``CheckpointStore``, then the ``SpotOnCoordinator``, then the
+    ``SpotTrainer`` on a scale set driven by ``schedule``. Returns
+    (trainer, accountant); ``trainer.run()`` executes the job and
+    ``trainer.coord.close()`` drains its writer. ``compile_cache_dir``
+    names a compile cache the program owns (``setup_compilation_cache``'s
+    return value); each checkpoint commit then sweeps it."""
+    from ..checkpoint import CheckpointStore
+    from ..core import (AZURE_D8S_V3, CheckpointPolicy, CostAccountant,
+                        ScaleSet, SpotOnCoordinator, StragglerDetector)
+    from ..optim import AdamWConfig
+    from ..train import SpotTrainer, TrainJob
+
+    accountant = CostAccountant(AZURE_D8S_V3)
+    pool = ScaleSet(clock=clock, schedule=schedule, accountant=accountant,
+                    provisioning_delay_s=provision_delay)
+    store = CheckpointStore(ckpt_dir, quantize_moments=quantize_moments)
+    if compile_cache_dir:
+        # cache hygiene rides the checkpoint cadence: after each commit the
+        # (rate-limited) sweep keeps the cache dir the program owns (see
+        # setup_compilation_cache) inside its size/age budget — off the
+        # save's critical path, never fatal
+        store.post_commit.append(
+            lambda d=compile_cache_dir: sweep_compilation_cache(d))
+    policy = {
+        "off": CheckpointPolicy.off(),
+        "application": CheckpointPolicy.application(),
+        "transparent": CheckpointPolicy.transparent(interval),
+    }[mode]
+    coord = SpotOnCoordinator(store, policy, clock,
+                              straggler=StragglerDetector())
+    job = TrainJob(cfg=cfg, opt=AdamWConfig(total_steps=steps),
+                   total_steps=steps, n_stages=stages, batch=batch,
+                   seq_len=seq_len, seed=seed, remat=remat,
+                   microbatches=microbatches)
+    trainer = SpotTrainer(job, coord, pool, clock, step_time_s=step_time_s)
+    return trainer, accountant
 
 
 def main(argv=None):
@@ -132,7 +172,9 @@ def main(argv=None):
     ap.add_argument("--stages", type=int, default=5)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq-len", type=int, default=64)
-    ap.add_argument("--ckpt-dir", default="/tmp/spoton_ckpts")
+    ap.add_argument("--ckpt-dir", default=".spoton_ckpts",
+                    help="checkpoint directory (default: .spoton_ckpts in "
+                         "the working directory)")
     ap.add_argument("--mode", choices=["off", "application", "transparent"],
                     default="transparent")
     ap.add_argument("--interval", type=float, default=60.0,
@@ -141,54 +183,34 @@ def main(argv=None):
                     help="inject an eviction every N seconds (0 = none)")
     ap.add_argument("--provision-delay", type=float, default=5.0)
     ap.add_argument("--quantize-moments", type=int, default=0)
-    ap.add_argument("--compile-cache-dir", default="",
-                    help="persistent XLA compilation cache (e.g. a dir on "
-                         "the checkpoint volume); empty disables")
+    ap.add_argument("--compile-cache-dir", default=".jax_cache",
+                    help="persistent XLA compilation cache directory, used "
+                         "and swept when JAX_COMPILATION_CACHE_DIR is unset "
+                         "(default: .jax_cache in the working directory)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--remat", default="none")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args(argv)
 
-    if args.compile_cache_dir:
-        setup_compilation_cache(args.compile_cache_dir)
+    cache_dir = setup_compilation_cache(args.compile_cache_dir)
 
     from ..configs import get_config, get_smoke_config
-    from ..checkpoint import CheckpointStore
-    from ..core import (AZURE_D8S_V3, CheckpointPolicy, CostAccountant, Mode,
-                        NoEviction, PeriodicEviction, ScaleSet,
-                        SpotOnCoordinator, StragglerDetector, WallClock)
-    from ..optim import AdamWConfig
-    from ..train import SpotTrainer, TrainJob
+    from ..core import NoEviction, PeriodicEviction, WallClock
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     clock = WallClock()
-    accountant = CostAccountant(AZURE_D8S_V3)
     schedule = PeriodicEviction(args.simulate_eviction_every) \
         if args.simulate_eviction_every else NoEviction()
-    pool = ScaleSet(clock=clock, schedule=schedule, accountant=accountant,
-                    provisioning_delay_s=args.provision_delay)
-    store = CheckpointStore(args.ckpt_dir,
-                            quantize_moments=bool(args.quantize_moments))
-    if args.compile_cache_dir:
-        # cache hygiene rides the checkpoint cadence: after each commit the
-        # (rate-limited) sweep keeps the shared cache dir inside its
-        # size/age budget — off the save's critical path, never fatal
-        store.post_commit.append(
-            lambda d=args.compile_cache_dir: sweep_compilation_cache(d))
-    policy = {
-        "off": CheckpointPolicy.off(),
-        "application": CheckpointPolicy.application(),
-        "transparent": CheckpointPolicy.transparent(args.interval),
-    }[args.mode]
-    coord = SpotOnCoordinator(store, policy, clock,
-                              straggler=StragglerDetector())
-    job = TrainJob(cfg=cfg, opt=AdamWConfig(total_steps=args.steps),
-                   total_steps=args.steps, n_stages=args.stages,
-                   batch=args.batch, seq_len=args.seq_len, seed=args.seed,
-                   remat=args.remat, microbatches=args.microbatches)
-    trainer = SpotTrainer(job, coord, pool, clock)
+    trainer, accountant = build_run(
+        cfg, clock=clock, schedule=schedule, ckpt_dir=args.ckpt_dir,
+        steps=args.steps, mode=args.mode, interval=args.interval,
+        stages=args.stages, batch=args.batch, seq_len=args.seq_len,
+        seed=args.seed, remat=args.remat, microbatches=args.microbatches,
+        provision_delay=args.provision_delay,
+        quantize_moments=bool(args.quantize_moments),
+        compile_cache_dir=cache_dir)
     report = trainer.run()
-    coord.close()
+    trainer.coord.close()
     summary = {
         "arch": cfg.name, "completed": report.completed,
         "total_time_s": round(report.total_time_s, 2),

@@ -24,7 +24,6 @@ Two time modes:
 
 from __future__ import annotations
 
-import logging
 import math
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
@@ -40,10 +39,8 @@ from ..core.spot_sim import InstancePool
 from ..data import PipelineState, TokenPipeline
 from ..models.config import ModelConfig
 from ..optim import AdamWConfig
-from .train_step import (init_train_state, make_train_step, state_template,
+from .train_step import (init_train_state, make_train_step,
                          state_template_on_device)
-
-log = logging.getLogger("spoton")
 
 
 @dataclass
@@ -145,12 +142,9 @@ class SpotTrainer:
             restored = self.coord.restore_latest(
                 state_template_on_device(template))
             if cfut is not None:
-                try:
-                    self._compiled_step = cfut.result()
-                except Exception as e:  # AOT is an optimization, never fatal:
-                    log.warning("step precompile failed; jit will compile at "
-                                "first dispatch: %s", e)
-                    self._compiled_step = None
+                # a failed precompile is the failure jit would hit at the
+                # first dispatch (device OOM, compiler error): surface it
+                self._compiled_step = cfut.result()
         finally:
             if compile_ex is not None:
                 compile_ex.shutdown(wait=False)
@@ -174,7 +168,9 @@ class SpotTrainer:
         sessions = 0
         last_session_max_step = 0
         final_loss = float("nan")
-        template = state_template(self._fresh_state())
+        # shapes and dtypes only: a zero-filled host copy of the state
+        # would hold as many host bytes as the state for the whole run
+        template = jax.eval_shape(self._fresh_state)
         self.pool.start()
         completed = False
 
@@ -182,9 +178,14 @@ class SpotTrainer:
             sessions += 1
             inst = self.pool.wait_for_instance()
             self.coord.attach_instance(inst.metadata, inst.name)
+            # the evicted session's state died with its instance; holding it
+            # (or the resume tuple below) through the next session would
+            # keep a whole extra state on the device beside the step's two
+            state = None
             resumed = self.resume(template)
             if resumed is not None:
                 state, _man, step, pstate = resumed
+                del resumed
             else:
                 state = self._fresh_state()
                 step = 0
@@ -276,6 +277,7 @@ class SpotTrainer:
             final_loss=final_loss,
             coordinator={
                 "periodic_ckpts": st.periodic_ckpts,
+                "periodic_failures": st.periodic_failures,
                 "termination_ckpts": st.termination_ckpts,
                 "termination_failures": st.termination_failures,
                 "rebalance_ckpts": st.rebalance_ckpts,

@@ -1,5 +1,8 @@
 """SpotOnCoordinator policy semantics — the paper's §III-A contract."""
 
+import errno
+
+import jax
 import numpy as np
 import pytest
 
@@ -172,3 +175,59 @@ class TestRestore:
     def test_restore_none_when_empty(self, tmp_path):
         coord, md, clock, store = make(tmp_path, CheckpointPolicy.transparent(1.0))
         assert coord.restore_latest(state(0)) is None
+
+
+class TestDeviceErrors:
+    """A device runtime error inside a save (OOM, failed compile, lost chip)
+    fails the run; storage faults keep their skip-and-alert degradation."""
+
+    @staticmethod
+    def _device_oom():
+        return jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    @pytest.mark.parametrize("wrapped", [False, True])
+    def test_periodic_save_device_error_propagates(self, tmp_path, monkeypatch,
+                                                   wrapped):
+        coord, md, clock, store = make(tmp_path, CheckpointPolicy.transparent(100.0))
+        err = self._device_oom()
+
+        def save_async(*a, **kw):
+            if wrapped:       # how an async writer's failure arrives
+                raise RuntimeError("async checkpoint write failed") from err
+            raise err
+        monkeypatch.setattr(coord._async, "save_async", save_async)
+        clock.advance(100.0)
+        with pytest.raises(RuntimeError) as ei:
+            coord.on_step_end(1, lambda: state(1))
+        assert ei.value is err or ei.value.__cause__ is err
+        assert coord.stats.periodic_failures == 0
+        assert coord.stats.saves_degraded == 0
+
+    def test_urgent_save_device_error_propagates(self, tmp_path, monkeypatch):
+        coord, md, clock, store = make(tmp_path, CheckpointPolicy.transparent(1e9))
+        err = self._device_oom()
+
+        def save_urgent(*a, **kw):
+            raise err
+        monkeypatch.setattr(coord._async, "save_urgent", save_urgent)
+        md.simulate_eviction()
+        clock.advance(2.0)
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            coord.on_step_end(7, lambda: state(7))
+        assert coord.stats.termination_failures == 0
+
+    def test_periodic_save_enospc_degrades(self, tmp_path, monkeypatch):
+        coord, md, clock, store = make(tmp_path, CheckpointPolicy.transparent(100.0))
+
+        def save_async(*a, **kw):
+            raise OSError(errno.ENOSPC, "No space left on device")
+        monkeypatch.setattr(coord._async, "save_async", save_async)
+        clock.advance(100.0)
+        assert coord.on_step_end(1, lambda: state(1)) is Signal.CONTINUE
+        assert coord.stats.periodic_failures == 1
+        assert coord.stats.saves_degraded == 1
+        # the next cadence lands inside the window: skipped, not retried
+        clock.advance(100.0)
+        coord.on_step_end(2, lambda: state(2))
+        assert coord.stats.periodic_failures == 1
+        assert coord.stats.saves_degraded == 2

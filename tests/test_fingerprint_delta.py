@@ -59,6 +59,36 @@ def test_fingerprint_pallas_interpret_parity(dtype, n):
     np.testing.assert_array_equal(ref, got)
 
 
+@pytest.mark.parametrize("dtype", [np.float32, ml_dtypes.bfloat16, np.int8])
+def test_fingerprint_pallas_interpret_parity_1mib_chunks(dtype):
+    # the default 1 MiB chunk: 2048 to 8192 rows per block, so the kernel
+    # walks each block in several row tiles
+    block = 1 << 20
+    a = _payload(dtype, 2 * block // np.dtype(dtype).itemsize + 999)
+    got = np.asarray(fingerprint_blocks(jnp.asarray(a), block_bytes=block,
+                                        interpret=True))
+    np.testing.assert_array_equal(fingerprint_blocks_ref(a, block), got)
+
+
+class _NoAsyncCopy:
+    def __init__(self, message):
+        self.message = message
+
+    def copy_to_host_async(self):
+        raise jax.errors.JaxRuntimeError(self.message)
+
+
+def test_copy_to_host_async_tolerates_only_unimplemented():
+    from repro.checkpoint.device_delta import copy_to_host_async
+    copy_to_host_async(_NoAsyncCopy("UNIMPLEMENTED: async host copy"))
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        copy_to_host_async(_NoAsyncCopy("INTERNAL: device lost"))
+    donated = jnp.arange(4.0)
+    donated.delete()
+    with pytest.raises(RuntimeError):              # a deleted buffer is a bug
+        copy_to_host_async(donated)
+
+
 def test_fingerprint_diff_matches_separate_compare():
     a = _payload(np.float32, 4 * CHUNK // 4)
     b = a.copy()
@@ -377,6 +407,51 @@ def test_sweep_compilation_cache_age_and_size(tmp_path):
                                    max_age_s=14 * 86400,
                                    min_interval_s=3600) == 0
     assert junk.exists()
+
+
+CACHE_PROBE = """
+import sys
+import jax, jax.numpy as jnp, numpy as np
+from repro.configs import get_smoke_config
+from repro.core import NoEviction, VirtualClock
+from repro.launch.train import build_run, setup_compilation_cache
+owned = setup_compilation_cache(sys.argv[1])
+print(owned, jax.config.jax_compilation_cache_dir)
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(8.0)).block_until_ready()
+trainer, _ = build_run(get_smoke_config("phi3-mini-3.8b"), clock=VirtualClock(),
+                       schedule=NoEviction(), ckpt_dir=sys.argv[2], steps=1,
+                       compile_cache_dir=owned)
+trainer.coord.store.save(0, {"w": np.ones(8, np.float32)})
+"""
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compilation_cache_lands_where_the_rule_says(tmp_path, env_set):
+    """JAX_COMPILATION_CACHE_DIR wins, nothing is set in code and no commit
+    sweeps that directory; without it the cache goes to the directory the
+    caller names, which the program owns and its commits sweep."""
+    import subprocess
+    import sys
+    env_dir, flag_dir = tmp_path / "env", tmp_path / "flag"
+    used, unused = (env_dir, flag_dir) if env_set else (flag_dir, env_dir)
+    used.mkdir()
+    stale = used / "jit_stale-cache"                # idle past the age gate
+    stale.write_bytes(b"x" * 100)
+    old = time.time() - 30 * 86400
+    os.utime(stale, (old, old))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_set:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run([sys.executable, "-c", CACHE_PROBE, str(flag_dir),
+                        str(tmp_path / "ckpt")],
+                       env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    owned = "None" if env_set else str(flag_dir)
+    assert r.stdout.split() == [owned, str(used)]
+    assert any(p != stale for p in used.iterdir())  # an executable was cached
+    assert not unused.exists()
+    assert stale.exists() == env_set                # swept only where owned
 
 
 def test_store_post_commit_hook_runs_and_never_fails_save(tmp_path):

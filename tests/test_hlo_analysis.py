@@ -123,3 +123,14 @@ def test_model_flops_sane():
     # MoE: active < total
     moe = get_config("grok1_314b")
     assert active_matmul_params(moe) < 0.45 * moe.param_count()
+
+
+def test_roofline_uses_the_device_kinds_peaks():
+    from repro.launch.roofline import roofline_terms
+    t = roofline_terms(per_device_flops=197e12, per_device_bytes=819e9 / 2,
+                       per_device_coll_bytes=0.0, device_kind="TPU v5 lite")
+    assert t["compute_s"] == 1.0 and t["memory_s"] == 0.5
+    assert t["dominant"] == "compute"
+    with pytest.raises(KeyError, match="no published peaks"):
+        roofline_terms(per_device_flops=1.0, per_device_bytes=1.0,
+                       per_device_coll_bytes=0.0, device_kind="TPU v4")

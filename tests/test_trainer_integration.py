@@ -3,6 +3,8 @@ workflow, including the headline property: transparent checkpointing makes an
 evicted run finish with BIT-EXACT final state and less wall time than
 application-stage checkpointing."""
 
+import weakref
+
 import numpy as np
 import pytest
 
@@ -58,6 +60,34 @@ class TestEvicted:
         # identical data order + full state capture => identical final loss
         assert ev.final_loss == pytest.approx(base.final_loss, abs=1e-6)
         assert ev.lost_steps == 0  # termination ckpt caught the frontier
+
+    def test_restored_state_released_by_the_first_step(self, tmp_path,
+                                                       monkeypatch):
+        """A session holds one state: once the first step after a restore
+        has replaced it, nothing keeps the restored tree alive, so the
+        device holds the step's input and output and no third copy."""
+        restored, alive_at_step_end = [], []
+        restore_latest = SpotOnCoordinator.restore_latest
+        on_step_end = SpotOnCoordinator.on_step_end
+
+        def track_restore(self, template, **kw):
+            out = restore_latest(self, template, **kw)
+            if out is not None:
+                big = max(jax.tree.leaves(out[0]), key=lambda a: a.size)
+                restored.append(weakref.ref(big))
+            return out
+
+        def track_step_end(self, step, state_provider, **kw):
+            if restored:
+                alive_at_step_end.append(restored[-1]() is not None)
+            return on_step_end(self, step, state_provider, **kw)
+
+        monkeypatch.setattr(SpotOnCoordinator, "restore_latest", track_restore)
+        monkeypatch.setattr(SpotOnCoordinator, "on_step_end", track_step_end)
+        ev, _ = run_job(tmp_path, "transparent", 250.0, periodic_s=100.0,
+                        tag="gc")
+        assert ev.completed and ev.restores >= 1
+        assert alive_at_step_end and not any(alive_at_step_end)
 
     def test_application_rolls_back_to_stage(self, tmp_path):
         ev, _ = run_job(tmp_path, "application", 420.0, tag="app")
