@@ -1,0 +1,11 @@
+"""The benchmark's own tests run on the CPU: ``python -m pytest bench/tests``.
+They import the harness from ``bench/`` and the program from ``src/``."""
+
+import os
+import sys
+
+BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ROOT = os.path.dirname(BENCH)
+for p in (BENCH, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
