@@ -217,6 +217,8 @@ def one_chip(cfg, *, batch: int = BATCH, seq_len: int = SEQ_LEN,
                   f"termination_ckpts={c['termination_ckpts']} "
                   f"restores={report.restores} lost_steps={report.lost_steps}")
             print(f"  save_stall_s={[round(s, 3) for s in stalls]} "
+                  "urgent_save_stall_s="
+                  f"{ledger.observed.get('urgent_save_stall')} "
                   f"urgent_save_wall_s={ledger.observed.get('urgent_save_wall')} "
                   f"restore_wall_s={ledger.total('restore_wall'):.3f}")
             print(f"  d2h_bytes={c['d2h_bytes']} "

@@ -102,13 +102,16 @@ class AsyncCheckpointer:
         With a ``tracker`` (device-delta, delta-mode stores) the extract leg
         moves only fingerprint-dirty blocks device→host; the tracker's
         commit bookkeeping runs on this writer thread once the store marks
-        the checkpoint COMMITTED."""
+        the checkpoint COMMITTED. The queue put, where a full queue holds
+        the trainer back, runs under a ``spoton.save.enqueue`` span."""
+        from ..core.ledger import span  # deferred: see chunkstore._retry
         self._raise_pending_error()
         snap = sharded.extract_snapshot(
             state, step=step, mesh_info=mesh_info,
             tracker=tracker if self.store.mode == "delta" else None)
         job = _Job(snapshot=snap, kind=kind, extra=extra, done=threading.Event())
-        self._queue.put(job)  # blocks if max_pending writes are outstanding
+        with span("save.enqueue"):
+            self._queue.put(job)  # blocks if max_pending writes are outstanding
         return snap
 
     def save_urgent(self, step: int, state, *, kind: str = "termination",

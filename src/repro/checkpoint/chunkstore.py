@@ -210,35 +210,39 @@ class ChunkPool:
         chunks pass ``sync_dir=False`` and sync the distinct dirty dirs once
         per save (see ``store_payload_chunks``) — the durability bar is only
         that every referenced chunk's rename is durable before the manifest
-        commits, not one fsync per chunk."""
+        commits, not one fsync per chunk. The physical write runs under a
+        ``spoton.save.pool_write`` span."""
+        from ..core.ledger import span  # deferred: see _retry
         path = self.path(h)
         if self.check(h, len(data)):
             self.touch(h)
             return 0
-        dirpath = os.path.dirname(path)
-        os.makedirs(dirpath, exist_ok=True)
-        tmp = path + f".tmp-{uuid.uuid4().hex[:8]}"
-        try:
-            with open(tmp, "wb") as f:
-                faults.write_bytes(f, data, op="chunk.write", path=tmp)
-                f.flush()
-                faults.fault_point("chunk.fsync", tmp)
-                os.fsync(f.fileno())
-            faults.fault_point("chunk.replace", path)
-            os.replace(tmp, path)   # atomic: readers never see partial chunks
-        except Exception:
-            # Quarantine: a failed/short tmp must not survive to be mistaken
-            # for progress — the retrying caller re-encodes from memory. A
-            # SimulatedCrash is a BaseException and skips this on purpose:
-            # a killed process leaves its debris for gc to reclaim.
+        with span("save.pool_write"):
+            dirpath = os.path.dirname(path)
+            os.makedirs(dirpath, exist_ok=True)
+            tmp = path + f".tmp-{uuid.uuid4().hex[:8]}"
             try:
-                os.unlink(tmp)
-            except OSError:
-                pass
-            raise
-        faults.fault_point("chunk.replaced", path, rollback=(path, tmp))
-        if sync_dir:
-            fsync_dir(dirpath)      # durable: rename survives a crash
+                with open(tmp, "wb") as f:
+                    faults.write_bytes(f, data, op="chunk.write", path=tmp)
+                    f.flush()
+                    faults.fault_point("chunk.fsync", tmp)
+                    os.fsync(f.fileno())
+                faults.fault_point("chunk.replace", path)
+                os.replace(tmp, path)   # atomic: readers never see partials
+            except Exception:
+                # Quarantine: a failed/short tmp must not survive to be
+                # mistaken for progress — the retrying caller re-encodes
+                # from memory. A SimulatedCrash is a BaseException and skips
+                # this on purpose: a killed process leaves its debris for gc
+                # to reclaim.
+                try:
+                    os.unlink(tmp)
+                except OSError:
+                    pass
+                raise
+            faults.fault_point("chunk.replaced", path, rollback=(path, tmp))
+            if sync_dir:
+                fsync_dir(dirpath)      # durable: rename survives a crash
         return len(data)
 
     def read_view(self, ref: ChunkRef) -> memoryview:

@@ -204,6 +204,8 @@ class _Staged:
         ent = self.ent
         if len(dirty) > self.tracker.dense_fallback_frac * len(ent.refs):
             self.dense = True
+            with self.tracker._lock:
+                self.tracker.stats["dense_fallbacks"] += 1
             copy_to_host_async(self.leaf)
             return
         now = time.monotonic()
@@ -301,10 +303,14 @@ class DeviceDeltaTracker:
         self._lock = threading.Lock()
         self._entries: dict[tuple[str, int], _Entry] = {}
         self._pending: dict[str, _Pending] = {}
-        # observability: decisions this process made, read by tests/benches
+        # observability: decisions this process made, read by tests/benches.
+        # ``fallbacks`` counts leaves whose previous entry was unusable;
+        # ``dense_fallbacks`` leaves that were diffed but so dirty that
+        # ``_Staged.resolve`` sent them down the dense path
         self.stats = {"tracked_saves": 0, "blocks_skipped": 0,
                       "blocks_transferred": 0, "fallbacks": 0,
-                      "rescale_events": 0, "fp_kept": 0, "fp_dropped": 0}
+                      "dense_fallbacks": 0, "rescale_events": 0,
+                      "fp_kept": 0, "fp_dropped": 0}
 
     # -- eligibility --------------------------------------------------------
 

@@ -143,16 +143,17 @@ def prestage(state, tracker: DeviceDeltaTracker | None = None):
     into the next step can never alias it). Non-eligible leaves stage the
     ordinary way. Urgent saves never pass a tracker.
     """
-    if tracker is not None:
-        named = ser.flatten_state(state)
-        for name, leaf in named.items():
-            if not tracker.prestage_leaf(name, leaf):
+    from ..core.ledger import span  # deferred: see chunkstore._retry
+    with span("save.prestage"):
+        if tracker is not None:
+            for name, leaf in ser.flatten_state(state).items():
+                if (not tracker.prestage_leaf(name, leaf)
+                        and isinstance(leaf, jax.Array)):
+                    _stage_async(leaf)
+        else:
+            for leaf in jax.tree_util.tree_leaves(state):
                 if isinstance(leaf, jax.Array):
                     _stage_async(leaf)
-        return state
-    for leaf in jax.tree_util.tree_leaves(state):
-        if isinstance(leaf, jax.Array):
-            _stage_async(leaf)
     return state
 
 
@@ -174,14 +175,19 @@ def extract_snapshot(state, *, step: int, mesh_info: dict | None = None,
     (``DeltaBlocks`` pieces), and unchanged blocks never cross the link.
     ``on_device_quantize`` and ``tracker`` are mutually exclusive by
     construction — urgent saves bypass fingerprinting entirely.
+
+    Spans: ``spoton.save.diff_wait`` around ``tracker.begin`` and each
+    leaf's diff resolve, ``spoton.save.d2h`` around the gather pass.
     """
+    from ..core.ledger import span  # deferred: see chunkstore._retry
     t_stall0 = time.perf_counter()
     named = ser.flatten_state(state)
     leaf_order = list(named)
     tracked: dict[str, Any] = {}
     commit_cb = None
     if tracker is not None and on_device_quantize is None:
-        tracked, commit_cb = tracker.begin(named)
+        with span("save.diff_wait"):
+            tracked, commit_cb = tracker.begin(named)
     prequant: dict[str, tuple[Any, Any]] = {}       # name -> (q_array, scale)
     if on_device_quantize is not None:
         from ..kernels.quantize import quantize_int8
@@ -192,7 +198,8 @@ def extract_snapshot(state, *, step: int, mesh_info: dict | None = None,
                 prequant[name] = quantize_int8(leaf)
     for name, leaf in named.items():                # phase 1: async staging
         if name in tracked:
-            tracked[name].resolve()     # diff sync + dirty-block gather
+            with span("save.diff_wait"):
+                tracked[name].resolve()     # diff sync + dirty-block gather
             continue
         staged = prequant[name][0] if name in prequant else leaf
         if isinstance(staged, jax.Array):
@@ -201,49 +208,54 @@ def extract_snapshot(state, *, step: int, mesh_info: dict | None = None,
     nbytes = 0
     d2h_bytes = 0
     d2h_skipped = 0
-    for name, leaf in named.items():                # phase 2: gather
-        if name in tracked:
-            res = tracked[name].finish()
-            if res is not None:
-                db, leaf_d2h, leaf_skip = res
-                leaves[name] = LeafPieces(
-                    db.shape, db.dtype_name,
-                    [(tuple((0, s) for s in db.shape), db)])
-                nbytes += db.nbytes
-                d2h_bytes += leaf_d2h
-                d2h_skipped += leaf_skip
-                continue
-            # high-churn dense fallback: gathered below like any other leaf
-        is_scalar_py = isinstance(leaf, (int, float, bool)) and not isinstance(leaf, np.generic)
-        pq, scale = None, None
-        if name in prequant:
-            src, dev_scale = prequant[name]
-            pq, scale = "int8", float(np.asarray(dev_scale))
-        else:
-            src = leaf
-        if isinstance(src, jax.Array) and not src.is_fully_replicated:
-            pieces = []
-            for shard in src.addressable_shards:
-                if shard.replica_id != 0:
+    with span("save.d2h"):
+        for name, leaf in named.items():            # phase 2: gather
+            if name in tracked:
+                res = tracked[name].finish()
+                if res is not None:
+                    db, leaf_d2h, leaf_skip = res
+                    leaves[name] = LeafPieces(
+                        db.shape, db.dtype_name,
+                        [(tuple((0, s) for s in db.shape), db)])
+                    nbytes += db.nbytes
+                    d2h_bytes += leaf_d2h
+                    d2h_skipped += leaf_skip
                     continue
-                arr = np.asarray(shard.data)
-                pieces.append((_slices_to_index(shard.index, src.shape), arr))
+                # high-churn dense fallback: gathered below like any
+                # other leaf
+            is_scalar_py = (isinstance(leaf, (int, float, bool))
+                            and not isinstance(leaf, np.generic))
+            pq, scale = None, None
+            if name in prequant:
+                src, dev_scale = prequant[name]
+                pq, scale = "int8", float(np.asarray(dev_scale))
+            else:
+                src = leaf
+            if isinstance(src, jax.Array) and not src.is_fully_replicated:
+                pieces = []
+                for shard in src.addressable_shards:
+                    if shard.replica_id != 0:
+                        continue
+                    arr = np.asarray(shard.data)
+                    pieces.append(
+                        (_slices_to_index(shard.index, src.shape), arr))
+                    nbytes += arr.nbytes
+                    d2h_bytes += arr.nbytes
+                lp = LeafPieces(tuple(src.shape),
+                                ser.dtype_to_name(leaf.dtype), pieces,
+                                prequant=pq or "", scale=scale)
+            else:
+                arr = ser.to_host(src)
                 nbytes += arr.nbytes
                 d2h_bytes += arr.nbytes
-            lp = LeafPieces(tuple(src.shape), ser.dtype_to_name(leaf.dtype),
-                            pieces, prequant=pq or "", scale=scale)
-        else:
-            arr = ser.to_host(src)
-            nbytes += arr.nbytes
-            d2h_bytes += arr.nbytes
-            lp = LeafPieces(
-                tuple(arr.shape), ser.dtype_to_name(leaf.dtype if pq
-                                                    else arr.dtype),
-                [(tuple((0, s) for s in arr.shape), arr)],
-                is_scalar_py=is_scalar_py, py_type=type(leaf).__name__,
-                prequant=pq or "", scale=scale,
-            )
-        leaves[name] = lp
+                lp = LeafPieces(
+                    tuple(arr.shape), ser.dtype_to_name(leaf.dtype if pq
+                                                        else arr.dtype),
+                    [(tuple((0, s) for s in arr.shape), arr)],
+                    is_scalar_py=is_scalar_py, py_type=type(leaf).__name__,
+                    prequant=pq or "", scale=scale,
+                )
+            leaves[name] = lp
     treedef = jax.tree_util.tree_structure(state)
     return Snapshot(step=step, leaves=leaves, leaf_order=leaf_order,
                     treedef_repr=str(treedef), mesh=mesh_info or {},
@@ -273,17 +285,20 @@ def write_snapshot(
     compress: bool = True,
     quantize_moments: bool = False,
 ) -> list[dict]:
-    """Write this process's shard container. Returns tensor records (+file)."""
+    """Write this process's shard container. Returns tensor records (+file).
+    Each piece's encode runs under a ``spoton.save.encode`` span."""
+    from ..core.ledger import span  # deferred: see chunkstore._retry
     pending = []
     for name, lp in snapshot.leaves.items():
         for pi, (index, arr) in enumerate(lp.pieces):
             codec = _piece_codec(name, lp, arr, compress=compress,
                                  quantize_moments=quantize_moments)
-            pending.append(ser.encode_tensor(
-                f"{name}#{pi}", arr, global_shape=lp.global_shape,
-                index=index, codec=codec,
-                prequant_scale=lp.scale if lp.prequant else None,
-                logical_dtype=lp.dtype if lp.prequant else None))
+            with span("save.encode", step=snapshot.step):
+                pending.append(ser.encode_tensor(
+                    f"{name}#{pi}", arr, global_shape=lp.global_shape,
+                    index=index, codec=codec,
+                    prequant_scale=lp.scale if lp.prequant else None,
+                    logical_dtype=lp.dtype if lp.prequant else None))
     fname = f"shard_p{process_index:03d}.spot"
     records = ser.write_shard_file(os.path.join(dirpath, fname), pending)
     out = []
@@ -292,6 +307,14 @@ def write_snapshot(
         d["file"] = fname
         out.append(d)
     return out
+
+
+def _encode_job(step: int, fn, *args):
+    """One encode job of a delta save, under a ``spoton.save.encode`` span
+    on the codec worker that runs it."""
+    from ..core.ledger import span  # deferred: see chunkstore._retry
+    with span("save.encode", step=step):
+        return fn(*args)
 
 
 def _delta_encode_piece(pool, key, arr, codec, chunk_size, index, pin,
@@ -347,7 +370,8 @@ def write_snapshot_delta(
             if isinstance(arr, DeltaBlocks):
                 # fingerprint-pruned piece: only its dirty blocks reached
                 # the host; clean blocks reuse the previous save's refs
-                fut = ex.submit(write_delta_blocks_piece, pool, (name, pi),
+                fut = ex.submit(_encode_job, snapshot.step,
+                                write_delta_blocks_piece, pool, (name, pi),
                                 arr, index, pin, dirty_dirs)
                 jobs.append((name, pi, idx, lp, arr, fut))
                 continue
@@ -356,9 +380,10 @@ def write_snapshot_delta(
             arr = np.asarray(arr)  # spotlint: ignore[SPOT021]
             codec = _piece_codec(name, lp, arr, compress=compress,
                                  quantize_moments=quantize_moments)
-            fut = ex.submit(_delta_encode_piece, pool, (name, pi), arr, codec,
-                            chunk_size, index, pin,
-                            lp.scale if lp.prequant else None, dirty_dirs)
+            fut = ex.submit(_encode_job, snapshot.step, _delta_encode_piece,
+                            pool, (name, pi), arr, codec, chunk_size, index,
+                            pin, lp.scale if lp.prequant else None,
+                            dirty_dirs)
             jobs.append((name, pi, idx, lp, arr, fut))
     try:
         results = [fut.result() for *_rest, fut in jobs]

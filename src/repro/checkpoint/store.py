@@ -244,6 +244,19 @@ class CheckpointStore:
 
     def save_snapshot(self, snapshot: sharded.Snapshot, *, kind: str = "transparent",
                       extra: dict | None = None) -> CheckpointInfo:
+        """Encode, write and commit ``snapshot``, under a
+        ``spoton.save.write`` span on the calling thread (the async writer's,
+        or the urgent save's) holding ``spoton.save.manifest`` and
+        ``spoton.save.commit``; the encode jobs' spans are on the codec
+        workers."""
+        from ..core.ledger import span  # deferred: see chunkstore._retry
+        with span("save.write", step=snapshot.step,
+                  kind="urgent" if kind == "termination" else "periodic"):
+            return self._save_snapshot(snapshot, kind=kind, extra=extra)
+
+    def _save_snapshot(self, snapshot: sharded.Snapshot, *, kind: str,
+                       extra: dict | None) -> CheckpointInfo:
+        from ..core.ledger import span  # deferred: see chunkstore._retry
         t0 = self.time_fn()
         if self._spooled_commits:
             # outage backlog first: parked steps must commit in order before
@@ -280,14 +293,16 @@ class CheckpointStore:
                     quantize_moments=self.quantize_moments)
                 new_bytes = sum(r["nbytes"] for r in records)
             self._phase("shards_written")
-            man = mf.Manifest(
-                step=snapshot.step, kind=kind, created_at=self.time_fn(),
-                tensors=records, leaf_order=snapshot.leaf_order,
-                treedef_repr=snapshot.treedef_repr, mesh=snapshot.mesh,
-                extra={**self.tags, **(extra or {})},
-                format_version=2 if self.mode == "delta" else 1,
-                chunk_size=self.chunk_size if self.mode == "delta" else None)
-            mf.write_manifest(stage, man)
+            with span("save.manifest"):
+                man = mf.Manifest(
+                    step=snapshot.step, kind=kind, created_at=self.time_fn(),
+                    tensors=records, leaf_order=snapshot.leaf_order,
+                    treedef_repr=snapshot.treedef_repr, mesh=snapshot.mesh,
+                    extra={**self.tags, **(extra or {})},
+                    format_version=2 if self.mode == "delta" else 1,
+                    chunk_size=(self.chunk_size if self.mode == "delta"
+                                else None))
+                mf.write_manifest(stage, man)
             self._phase("manifest_written")
             # Durability barrier before commit: with an object-store backend
             # every pipelined chunk upload must have landed before the
@@ -310,7 +325,8 @@ class CheckpointStore:
                     "(%d chunks awaiting upload); manifest parked until "
                     "reconcile", snapshot.step, len(undurable))
             else:
-                we_committed = self._finish_commit(stage, final, kind)
+                with span("save.commit"):
+                    we_committed = self._finish_commit(stage, final, kind)
         except BaseException:
             # leave staging dir for post-mortem; it is invisible to readers
             raise

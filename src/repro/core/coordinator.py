@@ -26,10 +26,12 @@ Periodic saves additionally run through the **device-delta tracker**
 (``checkpoint.device_delta``): per-block fingerprints stay device-resident
 between saves, so the extract leg moves only fingerprint-dirty blocks
 device→host — the modeled extract cost is charged on ``Snapshot.d2h_bytes``
-(the bytes that actually crossed the link), and
-``CoordinatorStats``/``TimeLedger`` record ``d2h_bytes`` /
-``d2h_bytes_skipped`` plus the extract stall so the saving is observable in
-every run report. Urgent and stage saves bypass the tracker.
+(the bytes that actually crossed the link), and ``CoordinatorStats``
+records ``d2h_bytes`` / ``d2h_bytes_skipped`` plus the extract stall so the
+saving is observable in every run report; the ledger observes each stall,
+``save_stall`` for scheduled saves and ``urgent_save_stall`` for urgent
+ones. Urgent and stage saves bypass the tracker. Each save's trainer-thread
+part runs under a ``spoton.save.extract`` span (``ledger.span``).
 Checkpoints written through the coordinator carry ``{"provider", "instance"}``
 tags in their manifest extras, so a fleet's shared store records which cloud
 wrote each checkpoint.
@@ -63,7 +65,7 @@ from ..checkpoint.store import CheckpointStore
 from ..faults import inject as fault_inject
 from . import retry
 from .clock import Clock, VirtualClock
-from .ledger import TimeLedger, TimeModel  # noqa: F401  (TimeModel re-export)
+from .ledger import TimeLedger, TimeModel, span  # noqa: F401  (TimeModel re-export)
 from .policy import CheckpointPolicy, Mode
 from .providers import (CloudProvider, PreemptNotice, PREEMPT_KIND,
                         REBALANCE_KIND, get_provider)
@@ -300,21 +302,20 @@ class SpotOnCoordinator:
 
     def _account_extract(self, snap: Snapshot | None = None, *,
                          d2h_bytes: int = 0, d2h_skipped: int = 0,
-                         stall_s: float = 0.0) -> None:
+                         stall_s: float = 0.0,
+                         stall_key: str = "save_stall") -> None:
         """Fold one extract's device→host traffic + stall into stats and the
-        ledger's audit trail (observations/counters, never clock charges —
-        the modeled extract cost is charged separately by the save paths).
-        Pass a Snapshot, or the raw numbers (the urgent path only has a
-        CheckpointInfo)."""
+        ledger's observations (never clock charges — the modeled extract
+        cost is charged separately by the save paths). Pass a Snapshot, or
+        the raw numbers (the urgent path only has a CheckpointInfo); urgent
+        saves observe their stall under ``urgent_save_stall``."""
         if snap is not None:
             d2h_bytes, d2h_skipped, stall_s = (snap.d2h_bytes,
                                                snap.d2h_skipped, snap.stall_s)
         self.stats.d2h_bytes += d2h_bytes
         self.stats.d2h_bytes_skipped += d2h_skipped
         self.stats.save_stall_s += stall_s
-        self.ledger.observe("save_stall", stall_s)
-        self.ledger.count("d2h_bytes", d2h_bytes)
-        self.ledger.count("d2h_bytes_skipped", d2h_skipped)
+        self.ledger.observe(stall_key, stall_s)
 
     def _drain_async_stats(self) -> None:
         """Fold finished background writes into the stats. Periodic/rebalance
@@ -327,26 +328,22 @@ class SpotOnCoordinator:
         if delta > 0:
             self._seen_yields = yields
             self.stats.save_yields += delta
-            self.ledger.count("save_yields", delta)
         io_retries = retry.snapshot_stats()["io_retries"]
         delta = io_retries - self._seen_io_retries
         if delta > 0:
             self._seen_io_retries = io_retries
             self.stats.io_retries += delta
-            self.ledger.count("io_retries", delta)
         injected = fault_inject.snapshot_stats()["faults_injected"]
         delta = injected - self._seen_faults
         if delta > 0:
             self._seen_faults = injected
             self.stats.faults_injected += delta
-            self.ledger.count("faults_injected", delta)
         bstats = chunk_backend.snapshot_stats()
         for key in ("backend_retries", "backend_outages", "spooled_bytes"):
             delta = bstats[key] - self._seen_backend[key]
             if delta > 0:
                 self._seen_backend[key] = bstats[key]
                 setattr(self.stats, key, getattr(self.stats, key) + delta)
-                self.ledger.count(key, delta)
         if self._async is None:
             return
         for info in self._async.drain_completed():
@@ -361,7 +358,6 @@ class SpotOnCoordinator:
 
     def _mark_degraded(self, e: BaseException) -> None:
         self.stats.saves_degraded += 1
-        self.ledger.count("saves_degraded", 1)
         self._degraded_until = self.clock.now() + self.degraded_cooldown_s
         log.warning(
             "storage degraded (%s): periodic checkpoints skip-and-alert "
@@ -381,45 +377,48 @@ class SpotOnCoordinator:
                 # The committed history is intact; count the skip so run
                 # reports surface the degradation window.
                 self.stats.saves_degraded += 1
-                self.ledger.count("saves_degraded", 1)
                 return False
             self._degraded_until = None  # cooldown over: probe storage again
-        # prestage at decision time: with the tracker, fingerprint + diff
-        # kernels dispatch now (dirty-block gather instead of full DMAs);
-        # without it, the device→host copies start before extract gathers
-        state = prestage(state, tracker=(self.delta_tracker
-                                         if self.store.mode == "delta"
-                                         else None))
-        try:
-            if self._async is not None:
-                snap = self._async.save_async(step, state, kind="transparent",
-                                              mesh_info=self.mesh_info,
-                                              extra=self._tags(),
-                                              tracker=self.delta_tracker)
-            else:
-                snap = extract_snapshot(
-                    state, step=step, mesh_info=self.mesh_info,
-                    tracker=(self.delta_tracker
-                             if self.store.mode == "delta" else None))
-                info = self.store.save_snapshot(snap, kind="transparent",
-                                                extra=self._tags())
-                self.stats.ckpt_bytes_written += info.new_bytes
-                if info.spooled:
-                    self._mark_degraded(RuntimeError(
-                        "object store outage: save spooled locally"))
-        except (RuntimeError, OSError) as e:
-            # a failed periodic save must not kill training: the committed
-            # history is untouched (atomic commit) and the next cadence
-            # retries with fresher state
-            _raise_device_fault(e)
-            log.warning("periodic checkpoint failed: %s", e)
-            self.stats.periodic_failures += 1
-            if _storage_fault(e):
-                # ENOSPC/EDQUOT/EROFS, or EIO that already exhausted the IO
-                # layer's bounded retries: a *state*, not an event — enter
-                # the skip-and-alert window instead of re-failing each tick
-                self._mark_degraded(e)
-            return False
+        # the span holds the trainer thread's part of the save: up to the
+        # queue put with an async writer, the whole write without one
+        with span("save.extract", step=step, kind="periodic"):
+            # prestage at decision time: with the tracker, fingerprint +
+            # diff kernels dispatch now (dirty-block gather instead of full
+            # DMAs); without it, the device→host copies start before
+            # extract gathers
+            tracker = (self.delta_tracker if self.store.mode == "delta"
+                       else None)
+            state = prestage(state, tracker=tracker)
+            try:
+                if self._async is not None:
+                    snap = self._async.save_async(
+                        step, state, kind="transparent",
+                        mesh_info=self.mesh_info, extra=self._tags(),
+                        tracker=tracker)
+                else:
+                    snap = extract_snapshot(
+                        state, step=step, mesh_info=self.mesh_info,
+                        tracker=tracker)
+                    info = self.store.save_snapshot(snap, kind="transparent",
+                                                    extra=self._tags())
+                    self.stats.ckpt_bytes_written += info.new_bytes
+                    if info.spooled:
+                        self._mark_degraded(RuntimeError(
+                            "object store outage: save spooled locally"))
+            except (RuntimeError, OSError) as e:
+                # a failed periodic save must not kill training: the
+                # committed history is untouched (atomic commit) and the
+                # next cadence retries with fresher state
+                _raise_device_fault(e)
+                log.warning("periodic checkpoint failed: %s", e)
+                self.stats.periodic_failures += 1
+                if _storage_fault(e):
+                    # ENOSPC/EDQUOT/EROFS, or EIO that already exhausted the
+                    # IO layer's bounded retries: a *state*, not an event —
+                    # enter the skip-and-alert window instead of re-failing
+                    # each tick
+                    self._mark_degraded(e)
+                return False
         self._account_extract(snap)
         # the extract leg is charged on the bytes that actually crossed the
         # link (the fingerprint path makes this ≪ state size at low churn);
@@ -447,27 +446,31 @@ class SpotOnCoordinator:
         # window cannot pay digest kernels whose results extract would then
         # discard — so the prestage is the plain full-state DMA kick
         w0 = _time.perf_counter()
-        state = prestage(state)
-        try:
-            if self._async is not None:
-                info = self._async.save_urgent(step, state, mesh_info=self.mesh_info,
-                                               extra=self._tags(),
-                                               timeout_s=max(budget, 0.1))
-            else:
-                snap = extract_snapshot(state, step=step, mesh_info=self.mesh_info)
-                info = self.store.save_snapshot(snap, kind="termination",
-                                                extra=self._tags())
-        except (TimeoutError, RuntimeError, OSError) as e:
-            _raise_device_fault(e)
-            log.warning("termination checkpoint failed: %s", e)
-            self.stats.termination_failures += 1
-            return False
+        # the trainer waits inside the span until the save is durable
+        with span("save.extract", step=step, kind="urgent"):
+            state = prestage(state)
+            try:
+                if self._async is not None:
+                    info = self._async.save_urgent(
+                        step, state, mesh_info=self.mesh_info,
+                        extra=self._tags(), timeout_s=max(budget, 0.1))
+                else:
+                    snap = extract_snapshot(state, step=step,
+                                            mesh_info=self.mesh_info)
+                    info = self.store.save_snapshot(snap, kind="termination",
+                                                    extra=self._tags())
+            except (TimeoutError, RuntimeError, OSError) as e:
+                _raise_device_fault(e)
+                log.warning("termination checkpoint failed: %s", e)
+                self.stats.termination_failures += 1
+                return False
         # wall time from the save's start to its durable commit: what the
         # provider's notice window has to cover
         self.ledger.observe("urgent_save_wall", _time.perf_counter() - w0)
         self._account_extract(d2h_bytes=info.d2h_bytes,
                               d2h_skipped=info.d2h_bytes_skipped,
-                              stall_s=info.save_stall_ms / 1e3)
+                              stall_s=info.save_stall_ms / 1e3,
+                              stall_key="urgent_save_stall")
         # extract covers the bytes that crossed the device→host link (the
         # full state for urgent saves — at 1/4 width for on-device-quantized
         # moments); the write leg is only the chunks the urgent save
@@ -491,9 +494,11 @@ class SpotOnCoordinator:
         if not self.policy.stage_boundary_enabled:
             return
         t0 = self.clock.now()
-        snap = extract_snapshot(state, step=step, mesh_info=self.mesh_info)
-        info = self.store.save_snapshot(snap, kind="application",
-                                        extra=self._tags(stage=stage))
+        with span("save.extract", step=step, kind="periodic"):
+            snap = extract_snapshot(state, step=step,
+                                    mesh_info=self.mesh_info)
+            info = self.store.save_snapshot(snap, kind="application",
+                                            extra=self._tags(stage=stage))
         self._account_extract(snap)
         # app-specific saves are synchronous in the app's critical path; the
         # write leg is physical bytes so the APPLICATION-vs-TRANSPARENT
@@ -532,7 +537,6 @@ class SpotOnCoordinator:
             self._poll_fail_streak += 1
             self.stats.poll_failures = max(self.stats.poll_failures,
                                            self._poll_fail_streak)
-            self.ledger.count("poll_failures", 1)
             log.warning("metadata poll failed (%d consecutive): %s",
                         self._poll_fail_streak, e)
             if self._poll_fail_streak % self.assume_evictable_after == 0:

@@ -10,13 +10,30 @@ attribute time across members sharing one clock.
 Wall-clock mode: charges are no-ops — durations are physical, the clock moves
 by itself. Virtual mode: ``charge`` advances the VirtualClock and records the
 amount under its category.
+
+``span`` is the program's other record of itself: named spans on the
+profiler's clock, which a device trace can line up with its idle gaps.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass, field
 
+import jax
+
 from .clock import Clock, VirtualClock
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A span ``spoton.<name>`` on the profiler's clock, used as a context
+    manager around one layer's work (a step's dispatch, a save's device to
+    host copy, one chunk's write).
+
+    While a trace is recording, the span lands on the host plane, on the
+    line of the thread that opened it, with ``args`` as its stats. With no
+    trace it costs one annotation enter and exit: it records nothing, waits
+    on no device and copies nothing; the profiler is the only recorder."""
+    return jax.profiler.TraceAnnotation("spoton." + name, **args)
 
 
 @dataclass(frozen=True)
@@ -52,7 +69,6 @@ class TimeLedger:
     time_model: TimeModel | None = None
     charged: dict[str, float] = field(default_factory=dict)
     observed: dict[str, list[float]] = field(default_factory=dict)
-    counted: dict[str, int] = field(default_factory=dict)
 
     @property
     def virtual(self) -> bool:
@@ -116,19 +132,6 @@ class TimeLedger:
 
     def observed_total(self, category: str) -> float:
         return sum(self.observed.get(category, ()))
-
-    # -- counters -------------------------------------------------------------
-
-    def count(self, category: str, amount: int) -> None:
-        """Accumulate a unitless quantity (bytes moved, bytes skipped) in the
-        audit trail. Counters never touch the clock — they exist so the
-        save path's device→host traffic (``d2h_bytes`` vs
-        ``d2h_bytes_skipped``) is visible in the same ledger that accounts
-        its time."""
-        self.counted[category] = self.counted.get(category, 0) + int(amount)
-
-    def counted_total(self, category: str) -> int:
-        return self.counted.get(category, 0)
 
     def total(self, category: str | None = None) -> float:
         if category is not None:

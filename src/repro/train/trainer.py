@@ -35,6 +35,7 @@ import numpy as np
 from ..checkpoint import sharded
 from ..core.clock import Clock
 from ..core.coordinator import Signal, SpotOnCoordinator
+from ..core.ledger import span
 from ..core.spot_sim import InstancePool
 from ..data import PipelineState, TokenPipeline
 from ..models.config import ModelConfig
@@ -157,6 +158,15 @@ class SpotTrainer:
         return state, man, step, pstate
 
     def run(self) -> RunReport:
+        """Run the job to completion across sessions. The step loop runs
+        under program spans (``ledger.span``): ``spoton.run`` around the
+        call; per iteration ``spoton.step`` with its parts ``step.batch``,
+        ``step.dispatch``, ``step.wait`` and ``step.hook`` in turn; and
+        ``spoton.flush`` around the wait for the last write."""
+        with span("run"):
+            return self._run()
+
+    def _run(self) -> RunReport:
         job = self.job
         clock = self.clock
         t_start = clock.now()
@@ -201,40 +211,51 @@ class SpotTrainer:
 
             preempted = False
             while step < job.total_steps:
-                if self.pool.tick() is None:       # platform killed the VM
-                    break
-                # the host-side cursor mirrors state["data"]["next_batch_index"]
-                # (both advance by 1 per step; resume() re-syncs from the
-                # restored state) — reading it here instead of the device
-                # cursor saves a device→host sync per step
-                batch = self.pipeline.batch_at(pstate.next_batch_index)
-                t0 = clock.now()
-                step_fn = (self._compiled_step if self._compiled_step is not None
-                           else self._step_fn)
-                state, metrics = step_fn(state, batch)
-                jax.block_until_ready(metrics["loss"])
-                pstate = PipelineState(pstate.next_batch_index + 1)
-                self.ledger.charge_step(self.step_time_s)
-                dur = clock.now() - t0
-                step += 1
-                steps_executed += 1
-                final_loss = float(np.asarray(metrics["loss"]))
-                # stage boundary bookkeeping + app-specific checkpoint hook
-                for si, b in enumerate(boundaries):
-                    if step == b:
-                        stage_cross_time[si] = clock.now()
-                        self.coord.on_stage_end(si, step, state)
-                # staging handoff: the supplier is invoked lazily, only when
-                # the coordinator decides to checkpoint. The coordinator owns
-                # the prestage call (it knows the save kind): periodic saves
-                # prestage through the device-delta tracker — fingerprint +
-                # diff compute instead of full-state DMAs — while urgent
-                # saves prestage the plain way, never paying digest kernels
-                # inside the eviction-notice window. The tracker's gathered
-                # blocks are fresh device buffers, so the next step may
-                # freely donate `state`.
-                sig = self.coord.on_step_end(step, lambda s=state: s,
-                                             step_duration_s=dur)
+                with span("step"):
+                    if self.pool.tick() is None:   # platform killed the VM
+                        break
+                    # the host-side cursor mirrors
+                    # state["data"]["next_batch_index"] (both advance by 1
+                    # per step; resume() re-syncs from the restored state) —
+                    # reading it here instead of the device cursor saves a
+                    # device→host sync per step
+                    with span("step.batch"):
+                        batch = self.pipeline.batch_at(
+                            pstate.next_batch_index)
+                    t0 = clock.now()
+                    step_fn = (self._compiled_step
+                               if self._compiled_step is not None
+                               else self._step_fn)
+                    with span("step.dispatch"):
+                        state, metrics = step_fn(state, batch)
+                    with span("step.wait"):
+                        jax.block_until_ready(metrics["loss"])
+                        final_loss = float(np.asarray(metrics["loss"]))
+                    pstate = PipelineState(pstate.next_batch_index + 1)
+                    self.ledger.charge_step(self.step_time_s)
+                    dur = clock.now() - t0
+                    step += 1
+                    steps_executed += 1
+                    with span("step.hook"):
+                        # stage boundary bookkeeping + app-specific
+                        # checkpoint hook
+                        for si, b in enumerate(boundaries):
+                            if step == b:
+                                stage_cross_time[si] = clock.now()
+                                self.coord.on_stage_end(si, step, state)
+                        # staging handoff: the supplier is invoked lazily,
+                        # only when the coordinator decides to checkpoint.
+                        # The coordinator owns the prestage call (it knows
+                        # the save kind): periodic saves prestage through
+                        # the device-delta tracker — fingerprint + diff
+                        # compute instead of full-state DMAs — while urgent
+                        # saves prestage the plain way, never paying digest
+                        # kernels inside the eviction-notice window. The
+                        # tracker's gathered blocks are fresh device
+                        # buffers, so the next step may freely donate
+                        # `state`.
+                        sig = self.coord.on_step_end(
+                            step, lambda s=state: s, step_duration_s=dur)
                 if sig is Signal.PREEMPTING:
                     preempted = True
                     break
@@ -250,7 +271,8 @@ class SpotTrainer:
                     clock.sleep(1.0)
             self.coord.detach()
 
-        self.coord.flush()
+        with span("flush"):
+            self.coord.flush()
         self.pool.shutdown()
         total = clock.now() - t_start
         # per-stage durations on the surviving lineage

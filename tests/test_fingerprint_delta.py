@@ -296,6 +296,20 @@ def test_high_churn_falls_back_dense(tmp_path):
             np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(v))
 
 
+@pytest.mark.parametrize("churn_rows,dense", [(64, 4), (8, 0)])
+def test_dense_fallbacks_are_counted(tmp_path, churn_rows, dense):
+    """Each diffed leaf that resolve sends down the dense path counts once in
+    ``dense_fallbacks``; a low-churn save gathers blocks and counts none."""
+    store = CheckpointStore(str(tmp_path), mode="delta", chunk_size=CHUNK)
+    tracker = _tracker_for(store)
+    store.save(0, _state(0, churn_rows=churn_rows), tracker=tracker)
+    assert tracker.stats["dense_fallbacks"] == 0     # nothing to diff yet
+    store.save(1, _state(1, churn_rows=churn_rows), tracker=tracker)
+    assert tracker.stats["tracked_saves"] == 1
+    assert tracker.stats["dense_fallbacks"] == dense
+    assert (tracker.stats["blocks_transferred"] == 0) == (dense == 4)
+
+
 def test_prestage_with_tracker_feeds_extract(tmp_path):
     """The trainer supplier path: prestage dispatches fingerprint+diff, the
     subsequent extract consumes the pending work and produces DeltaBlocks."""
@@ -350,7 +364,7 @@ def test_prestaged_diff_discarded_when_entry_swaps(tmp_path):
 
 def test_coordinator_accounts_d2h(tmp_path):
     """Periodic saves through the coordinator surface d2h/skip/stall in
-    CoordinatorStats and the TimeLedger counters."""
+    CoordinatorStats and the stalls in the TimeLedger's observations."""
     import dataclasses
 
     from repro.core import CheckpointPolicy, SpotOnCoordinator, WallClock
@@ -367,9 +381,30 @@ def test_coordinator_accounts_d2h(tmp_path):
     assert st.d2h_bytes > 0
     assert st.d2h_bytes_skipped > 0                 # second save skipped blocks
     assert st.save_stall_s > 0
-    assert coord.ledger.counted_total("d2h_bytes") == st.d2h_bytes
-    assert coord.ledger.counted_total("d2h_bytes_skipped") == st.d2h_bytes_skipped
     assert len(coord.ledger.observed.get("save_stall", [])) == 2
+
+
+def test_urgent_stall_observed_apart_from_periodic(tmp_path):
+    """The ledger keeps an urgent save's stall under ``urgent_save_stall``:
+    ``save_stall`` holds the periodic saves' alone, while
+    ``CoordinatorStats.save_stall_s`` still totals both."""
+    import dataclasses
+
+    from repro.core import CheckpointPolicy, SpotOnCoordinator, WallClock
+
+    store = CheckpointStore(str(tmp_path), mode="delta", chunk_size=CHUNK)
+    policy = dataclasses.replace(CheckpointPolicy.transparent(1e9),
+                                 async_writes=False)
+    coord = SpotOnCoordinator(store, policy, WallClock())
+    assert coord.save_periodic_now(0, _state(0))
+    assert coord._save_termination(1, _state(1),
+                                   deadline=coord.clock.now() + 3600.0)
+    observed = coord.ledger.observed
+    assert len(observed["save_stall"]) == 1
+    assert len(observed["urgent_save_stall"]) == 1
+    assert len(observed["urgent_save_wall"]) == 1
+    assert coord.stats.save_stall_s == pytest.approx(
+        observed["save_stall"][0] + observed["urgent_save_stall"][0])
 
 
 # ---------------------------------------------------------------------------
