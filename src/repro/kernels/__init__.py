@@ -6,12 +6,13 @@ TPU is the compile target.
 
 Two families run in the checkpoint path on a TPU: ``fingerprint`` (the
 device-delta tracker's per-block digests) and ``quantize`` (on-device int8
-moments for urgent saves, and the restore's dequantize);
-``tests/test_tpu_compile.py`` compiles both for a described v5e. The
-attention and scan kernels (flash/decode attention, ``ssm_scan``,
-``rglru_scan``) mirror the model's XLA paths (models/layers.py chunked
-attention, associative scans) and are tested against them, but ``models/``
-never calls them: training and serving run the XLA paths on every backend.
+moments for urgent saves, and the restore's dequantize). ``flash_attention``
+runs the model's sequence attention on a TPU (``models.transformer``
+dispatches to it by backend and shape). ``tests/test_tpu_compile.py``
+compiles all three for a described v5e. The decode attention and scan
+kernels (``flash_decode``, ``ssm_scan``, ``rglru_scan``) mirror the model's
+XLA paths (single-token attention, associative scans) and are tested
+against them, but ``models/`` never calls them.
 """
 
 from .decode_attention import decode_attention_ref, flash_decode

@@ -28,7 +28,9 @@ from . import moe as MOE
 from . import rglru as RG
 from . import ssm as SSM
 from .config import ModelConfig, RGLRUConfig, SSMConfig
-from ..distributed.sharding import shard_act
+from ..distributed.sharding import current_rules, shard_act
+from ..kernels.flash_attention import flash_attention
+from ..kernels.flash_attention import supports as flash_supports
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +232,33 @@ def _channel_mix(x, w, cfg: ModelConfig, *, decode: bool = False):
     return y, jnp.zeros((), jnp.float32)
 
 
-def _attn_mix(x, w, cfg: ModelConfig, kind: str, positions, q_offset=0,
-              kv=None, kv_valid_len=None):
+def _tpu_backend() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _flash_engages(q, k) -> bool:
+    """The Pallas kernel takes sequence attention on a TPU, on one device
+    (no sharding rules, or a mesh of one), at shapes it was compiled and
+    tested for; every other case keeps XLA's chunked path."""
+    rules = current_rules()
+    return (_tpu_backend() and (rules is None or rules.mesh.size == 1)
+            and flash_supports(q.shape, k.shape))
+
+
+def _sequence_attention(q, k, v, window: int):
+    """Causal self-attention over the whole sequence (q_offset 0, no cache).
+    The kernel's custom VJP keeps only O(S) residuals (q, k, v, o and the
+    log-sum-exp). XLA's path runs under its own checkpoint, which saves only
+    q, k, v and recomputes the score and softmax intermediates in backward
+    (otherwise any remat-dots policy pins O(S·ctx) score matrices per
+    layer)."""
+    if _flash_engages(q, k):
+        return flash_attention(q, k, v, window=window)
+    return jax.checkpoint(
+        lambda q_, k_, v_: L.attention(q_, k_, v_, window=window))(q, k, v)
+
+
+def _attn_mix(x, w, cfg: ModelConfig, kind: str, positions):
     """Attention residual branch (sequence). Returns (delta, (k, v))."""
     B, S, D = x.shape
     q = (x @ w["wq"]).reshape(B, S, cfg.n_heads, cfg.head_dim)
@@ -243,14 +270,7 @@ def _attn_mix(x, w, cfg: ModelConfig, kind: str, positions, q_offset=0,
     q = shard_act(q, "heads")
     k = shard_act(k, "kv_heads")
     v = shard_act(v, "kv_heads")
-    # flash-attention residency policy: save only q,k,v and the output;
-    # score/softmax intermediates are recomputed in backward (otherwise any
-    # remat-dots policy pins O(S·ctx) score matrices per layer).
-    attn_fn = jax.checkpoint(
-        lambda q_, k_, v_: L.attention(q_, k_, v_, q_offset=q_offset,
-                                       window=_kind_window(cfg, kind),
-                                       kv_valid_len=kv_valid_len))
-    out = attn_fn(q, k, v)
+    out = _sequence_attention(q, k, v, _kind_window(cfg, kind))
     return out.reshape(B, S, cfg.q_dim) @ w["wo"], (k, v)
 
 

@@ -17,37 +17,39 @@ def _rand(key, shape, dtype):
 
 
 FLASH_CASES = [
-    # B, S, H, KV, hd, window, bq, bk, dtype, tol
-    (2, 256, 4, 2, 64, 0, 128, 128, jnp.float32, 2e-5),
-    (1, 256, 4, 1, 64, 64, 64, 64, jnp.float32, 2e-5),
-    (2, 192, 2, 2, 32, 0, 128, 128, jnp.float32, 2e-5),  # padding path
-    (1, 128, 8, 4, 128, 0, 128, 128, jnp.float32, 2e-5),
-    (1, 256, 4, 4, 64, 0, 128, 128, jnp.bfloat16, 2e-2),
-    (1, 384, 2, 1, 64, 128, 128, 128, jnp.float32, 2e-5),
+    # B, S, H, KV, hd, window, dtype, tol
+    (2, 256, 4, 2, 64, 0, jnp.float32, 2e-5),
+    (1, 256, 4, 1, 64, 64, jnp.float32, 2e-5),
+    (2, 256, 2, 2, 96, 255, jnp.float32, 2e-5),   # window S-1: one pair masked
+    (1, 128, 8, 4, 128, 0, jnp.float32, 2e-5),
+    (1, 256, 4, 4, 64, 0, jnp.bfloat16, 2e-2),
+    (1, 384, 2, 1, 64, 128, jnp.float32, 2e-5),   # 128-row blocks
+    (1, 256, 2, 1, 256, 100, jnp.float32, 2e-5),
 ]
 
 
-@pytest.mark.parametrize("B,S,H,KV,hd,window,bq,bk,dtype,tol", FLASH_CASES)
-def test_flash_attention_fwd(B, S, H, KV, hd, window, bq, bk, dtype, tol):
+@pytest.mark.parametrize("B,S,H,KV,hd,window,dtype,tol", FLASH_CASES)
+def test_flash_attention_fwd(B, S, H, KV, hd, window, dtype, tol):
     ks = jax.random.split(jax.random.key(S + H), 3)
     q = _rand(ks[0], (B, S, H, hd), dtype)
     k = _rand(ks[1], (B, S, KV, hd), dtype)
     v = _rand(ks[2], (B, S, KV, hd), dtype)
-    o = flash_attention(q, k, v, True, window, bq, bk, True)
+    o = flash_attention(q, k, v, window=window, interpret=True)
     o_ref = attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), atol=tol)
 
 
-@pytest.mark.parametrize("B,S,H,KV,hd,window,bq,bk,dtype,tol", FLASH_CASES[:4])
-def test_flash_attention_grads(B, S, H, KV, hd, window, bq, bk, dtype, tol):
+@pytest.mark.parametrize("B,S,H,KV,hd,window,dtype,tol", FLASH_CASES[:4])
+def test_flash_attention_grads(B, S, H, KV, hd, window, dtype, tol):
     ks = jax.random.split(jax.random.key(S * H), 3)
     q = _rand(ks[0], (B, S, H, hd), dtype)
     k = _rand(ks[1], (B, S, KV, hd), dtype)
     v = _rand(ks[2], (B, S, KV, hd), dtype)
 
     def f(q, k, v):
-        return jnp.sum(jnp.sin(flash_attention(q, k, v, True, window, bq, bk, True)))
+        return jnp.sum(jnp.sin(flash_attention(q, k, v, window=window,
+                                               interpret=True)))
 
     def fr(q, k, v):
         return jnp.sum(jnp.sin(attention_ref(q, k, v, causal=True, window=window)))
