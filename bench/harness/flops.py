@@ -1,25 +1,14 @@
-"""Operations and bytes the work needs, counted from shapes.
+"""Operations and bytes the work needs, counted from shapes alone.
 
-Model FLOPs of one training step count the forward and backward passes
-(backward = twice forward) and leave recomputation out: a step with full
-rematerialization does more, and that surplus is not useful work.
-Attention is counted causal: only the query-key pairs the mask keeps, and
-within a sliding window only the pairs inside it. The embedding lookup is
-not a matmul and is not counted; the output head is.
+What depends on an architecture is counted by the configuration's program
+module (``spec.program_module``): its ``work(cfg)`` gives the model FLOPs
+of one training step (``step_flops``) and those of one attention kernel
+call (``attention_fwd_flops``, ``attention_bwd_flops``), built from the
+helpers here. Attention is counted causal: only the query-key pairs the
+mask keeps, and within a sliding window only the pairs inside it.
 """
 
 from __future__ import annotations
-
-
-def matmul_params(cfg: dict) -> int:
-    """Matmul weights one token passes through (dense decoder)."""
-    d = cfg["hidden_size"]
-    hd = d // cfg["num_attention_heads"]
-    q = cfg["num_attention_heads"] * hd
-    kv = cfg["num_key_value_heads"] * hd
-    f = cfg["intermediate_size"]
-    per_layer = d * q + 2 * d * kv + q * d + 3 * d * f     # gated MLP
-    return cfg["num_hidden_layers"] * per_layer + d * cfg["vocab_size"]
 
 
 def attention_pairs(seq_len: int, window: int | None) -> int:
@@ -30,16 +19,17 @@ def attention_pairs(seq_len: int, window: int | None) -> int:
     return w * (w + 1) // 2 + (seq_len - w) * w
 
 
-def train_step_flops(cfg: dict, batch: int, seq_len: int) -> float:
-    """Model FLOPs of one forward + backward pass over ``batch`` rows."""
-    tokens = batch * seq_len
-    dense = 6.0 * matmul_params(cfg) * tokens
-    hd = cfg["hidden_size"] // cfg["num_attention_heads"]
-    # QK^T and PV: 2 FLOPs per multiply-add each, per kept pair and head
-    attn_fwd = (4.0 * cfg["num_attention_heads"] * hd
-                * attention_pairs(seq_len, cfg.get("sliding_window"))
-                * batch * cfg["num_hidden_layers"])
-    return dense + 3.0 * attn_fwd
+def attention_call_flops(batch: int, heads: int, seq_len: int,
+                         window: int | None, d_qk: int,
+                         d_v: int) -> tuple[float, float]:
+    """(forward, backward) FLOPs of one causal self-attention kernel call
+    over ``batch`` rows of ``heads`` query heads: QK^T (``d_qk`` a pair) and
+    PV (``d_v``), 2 FLOPs per multiply-add, per kept pair. The backward
+    computes dP, dV, dQ and dK, twice the forward; the scores it computes
+    again are not useful work and are not counted."""
+    fwd = (2.0 * batch * heads * attention_pairs(seq_len, window)
+           * (d_qk + d_v))
+    return fwd, 2.0 * fwd
 
 
 def fingerprint_bytes(leaf_nbytes, min_bytes: int = 1 << 16) -> int:

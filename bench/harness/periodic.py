@@ -101,11 +101,9 @@ def setup(cell: spec.Cell, seed: int, *, root: str, cache_dir) -> Setup:
     from repro.launch.train import build_run
     from repro.train.train_step import init_train_state
 
-    from .program import model_config
-
     cfg, tr = cell.config, cell.traffic
     train = cfg["train"]
-    mcfg = model_config(cfg)
+    mcfg = spec.program_module(cfg).model_config(cfg)
     ckpt_dir = os.path.join(root, ".spoton_ckpts", cell.name)
     shutil.rmtree(ckpt_dir, ignore_errors=True)
     trainer, _ = build_run(
@@ -327,7 +325,5 @@ def run(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
 
     return record.traced_result(
         result, device, tdir, cell=cell, window_s=window_s,
-        step_flops=flops.train_step_flops(cfg, train["batch"],
-                                          train["seq_len"]),
-        observed=observed,
+        work=spec.program_module(cfg).work(cfg), observed=observed,
         fingerprint_bytes=flops.fingerprint_bytes(leaf_bytes)), checks

@@ -14,7 +14,7 @@ from . import spec
 class RunRecord:
     cell: spec.Cell
     window_s: float            # host seconds of the traced window
-    step_flops: float = 0.0    # model FLOPs of one step (harness.flops)
+    work: dict = field(default_factory=dict)       # program module's work()
     peaks: dict = field(default_factory=dict)
     trace: object = None       # harness.trace.Trace, or None
     observed: dict = field(default_factory=dict)   # program spans, by name

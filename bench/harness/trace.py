@@ -54,6 +54,20 @@ class Trace:
         k = max(len(self.devices), 1)
         return secs / k / 1e9, n // k
 
+    def op_time_s(self, match) -> tuple[float, int]:
+        """Self seconds and executions of the operations whose short name
+        (``short_op_name``) ``match`` accepts, as ``top_ops`` sums them,
+        averaged over chips. A kernel inside a program is an operation of
+        it (a custom call), seen here and not by ``module_time_s``."""
+        secs = n = 0
+        for d in self.devices.values():
+            for name, self_ns in self_times(d.ops):
+                if match(short_op_name(name)):
+                    secs += self_ns
+                    n += 1
+        k = max(len(self.devices), 1)
+        return secs / k / 1e9, n // k
+
     def top_modules(self, k: int = 10) -> list:
         """[[name, seconds, executions]] of the programs with most device
         time, averaged over chips."""

@@ -21,12 +21,10 @@ CPU_LIMITS = {"loss_gap": 1e-4, "grad_gap": 3e-3, "change_gap": 5e-3}
 
 
 def smoke_cell(name: str, *, limits=None) -> spec.Cell:
+    """The cell with its configuration cut by its program module's
+    ``smoke``."""
     cell = spec.load_cell(name)
-    cfg = dict(cell.config)
-    cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-               num_key_value_heads=4, num_hidden_layers=2, vocab_size=512,
-               sliding_window=31)
-    cfg["train"] = dict(cfg["train"], seq_len=64)
+    cfg = spec.program_module(cell.config).smoke(cell.config)
     traffic = dict(cell.traffic)
     if "min_steps_after_save" in traffic:
         traffic["min_steps_after_save"] = 6

@@ -6,20 +6,44 @@ import pytest
 
 from harness import compare, flops, peaks, spec
 
+# phi3-mini-3l's model FLOPs of one step, as harness.flops counted them
+# before the count moved to the configuration's program module
+STEP_FLOPS = 11_234_250_104_832.0
+
 
 def test_phi3_mini_3l_step_flops():
     cfg = spec.load_cell("phi3-mini-3l.periodic").config
+    program = spec.program_module(cfg)
     # 3 layers of 4 x 3072^2 attention and 3 x 3072 x 8192 MLP weights,
     # plus the 3072 x 32064 head
-    assert flops.matmul_params(cfg) == 3 * (4 * 3072 ** 2
-                                            + 3 * 3072 * 8192) + 3072 * 32064
+    assert program.matmul_params(cfg) == 3 * (4 * 3072 ** 2
+                                              + 3 * 3072 * 8192) + 3072 * 32064
     pairs = 2047 * 2048 // 2 + 1 * 2047          # window 2047 of 2048
     assert flops.attention_pairs(2048, 2047) == pairs
     assert flops.attention_pairs(2048, None) == 2048 * 2049 // 2
-    want = (6.0 * flops.matmul_params(cfg) * 4096
+    want = (6.0 * program.matmul_params(cfg) * 4096
             + 3 * 4.0 * 32 * 96 * pairs * 2 * 3)
-    assert flops.train_step_flops(cfg, 2, 2048) == want
+    work = program.work(cfg)
+    assert work["step_flops"] == want
+    # the number step_mfu has divided by since it was first measured
+    assert work["step_flops"] == STEP_FLOPS
     assert 11.1e12 < want < 11.3e12
+    # one kernel call: one layer, both rows, head size 96 for q, k and v
+    assert work["attention_fwd_flops"] == 2.0 * 2 * 32 * pairs * 192
+    assert work["attention_bwd_flops"] == 2 * work["attention_fwd_flops"]
+
+
+@pytest.mark.parametrize("d_qk,d_v,gflop", [
+    (96, 96, 51.5647488),       # phi3-mini: one head size for q, k and v
+    (192, 128, 85.941248),      # latent attention: q.k over 192, v 128
+])
+def test_attention_call_flops(d_qk, d_v, gflop):
+    """One kernel call at phi3-mini's (2, 2048, 32 heads, window 2047):
+    QK^T and PV over the 2,098,175 kept pairs; the backward twice that."""
+    fwd, bwd = flops.attention_call_flops(2, 32, 2048, 2047, d_qk, d_v)
+    assert fwd == 2.0 * 2 * 32 * 2_098_175 * (d_qk + d_v)
+    assert fwd / 1e9 == pytest.approx(gflop)
+    assert bwd == 2 * fwd
 
 
 def test_fingerprint_bytes_skip_small_leaves():
